@@ -1,6 +1,6 @@
 """Kernel-path knobs for the benchmark runner.
 
-`benchmarks.run --use-pallas [--no-interpret]` exports the launch profile to
+`benchmarks.run --use-pallas [--interpret]` exports the launch profile to
 the individual benches through the environment (the bench modules are plain
 `run()` functions), mirroring the `launch/fleet.py` CLI contract: benches
 thread `**pallas_knobs()` into their solver calls, so a TPU/GPU deployment
@@ -19,5 +19,5 @@ def pallas_knobs() -> dict:
         return {}
     return {
         "use_pallas": True,
-        "interpret": os.environ.get("REPRO_BENCH_INTERPRET", "1") != "0",
+        "interpret": os.environ.get("REPRO_BENCH_INTERPRET") == "1",
     }

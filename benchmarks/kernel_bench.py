@@ -2,7 +2,7 @@
 validated in interpret mode (wall-clock on CPU interpret is meaningless —
 the TPU perf argument lives in the roofline analysis).
 
-`benchmarks.run --use-pallas [--no-interpret]` routes the apsp section (and
+`benchmarks.run --use-pallas [--interpret]` routes the apsp section (and
 the fleet benches) through the Pallas kernels instead — see _knobs.py."""
 from __future__ import annotations
 

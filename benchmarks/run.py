@@ -342,10 +342,9 @@ def main() -> int:
     ap.add_argument(
         "--interpret",
         action=argparse.BooleanOptionalAction,
-        default=True,
+        default=False,
         help="with --use-pallas, run kernel bodies under the Pallas "
-        "interpreter (CPU validation). A real TPU/GPU benchmark run passes "
-        "--use-pallas --no-interpret; no effect without --use-pallas",
+        "interpreter (CPU only; off by default)",
     )
     args = ap.parse_args()
     if args.use_pallas:

@@ -90,7 +90,7 @@ def refresh_apsp0(
     hop_bound=None,
     n_parts=None,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> Apsp0Cache:
     """Return a cache valid for this epoch's problems (hit or recompute).
 
@@ -137,7 +137,7 @@ def repair_fleet(
     hop_bound=None,
     n_parts=None,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
     apsp0: Apsp0Cache | None = None,
 ) -> State:
     """Evict every dead-hosted partition across a fleet in one vmapped call.

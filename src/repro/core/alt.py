@@ -87,7 +87,7 @@ def solve_alt(
     patience: int = 4,
     colocate: bool = False,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
     solver: str = "neumann",
     block_apps: int = 1,
     name: str = "ALT",
@@ -124,7 +124,7 @@ def solve_oneshot(
     t_phi: int = 10,
     alpha: float = 0.5,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
     solver: str = "neumann",
     block_apps: int = 1,
 ) -> Result:
@@ -171,7 +171,7 @@ def solve_congunaware(
     problem: Problem,
     *,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
     solver: str = "neumann",
 ) -> Result:
     """Shortest extended path under linear costs, evaluated with true costs.
@@ -201,7 +201,7 @@ def solve_colocated(
     tol: float = 1e-3,
     patience: int = 4,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
     solver: str = "neumann",
     block_apps: int = 1,
 ) -> Result:

@@ -186,7 +186,7 @@ def round_step(
     colocate: bool,
     use_pallas: bool,
     solver: str,
-    interpret: bool = True,
+    interpret: bool = False,
     block_apps: int = 1,
 ) -> EngineCarry:
     """One batched ALT round: Algorithm 1's loop body plus bookkeeping.
@@ -399,7 +399,7 @@ def engine_solve(
     colocate: bool = False,
     track_best: bool = True,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
     solver: str = "neumann",
     trace: bool = True,
     block_apps: int = 1,

@@ -36,6 +36,9 @@ from ..kernels.neumann import effective_hops, neumann_solve
 from .structs import Apps, Problem, State, one_hot, partition_live_mask
 
 SOLVERS = ("neumann", "lu")
+# fp32 products on the TPU too, where XLA's default rounds dot operands to
+# bf16: the link and node loads feed J directly.
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def stage_solve(
@@ -46,7 +49,7 @@ def stage_solve(
     transpose: bool,
     solver: str = "neumann",
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Batched (I - Phi^T) t = b (transpose=True) or (I - Phi) q = c solve.
 
@@ -54,8 +57,8 @@ def stage_solve(
     b: [..., V]. The hop cap comes from the Problem-carried bound.
 
     `interpret=True` runs the Pallas kernel body under the interpreter
-    (CPU validation); a real TPU/GPU launch passes `--use-pallas
-    --no-interpret` at the CLI and the pair flows down here unchanged.
+    (CPU tests only); a launch passes `--use-pallas` at the CLI and the
+    pair flows down here unchanged.
     """
     if solver == "lu":
         n = phi_k.shape[-1]
@@ -84,7 +87,7 @@ def _stage_gates(state: State, apps: Apps) -> jax.Array:
     return jnp.moveaxis(gates, 1, 0)
 
 
-def _traffic_scan(problem, state, inject, *, solver, use_pallas, interpret=True):
+def _traffic_scan(problem, state, inject, *, solver, use_pallas, interpret=False):
     """Forward stage scan: t_k = solve(phi_k, inject_k + gate_k * t_{k-1})."""
     solve = partial(
         stage_solve, problem=problem, transpose=True, solver=solver,
@@ -120,7 +123,7 @@ def stage_traffic(
     *,
     solver: str = "neumann",
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """[A, K, V] traffic rate t_i^{a,k} (requests/s)."""
     return _traffic_scan(
@@ -150,7 +153,7 @@ def loads(problem: Problem, state: State, t: jax.Array | None = None):
 
     def accum_f(acc, xs):
         L_k, f_k = xs  # [A], [A, V, V]
-        return acc + jnp.einsum("a,aij->ij", L_k, f_k), None
+        return acc + jnp.einsum("a,aij->ij", L_k, f_k, precision=HIGHEST), None
 
     n = state.phi.shape[-1]
     F, _ = jax.lax.scan(
@@ -162,7 +165,9 @@ def loads(problem: Problem, state: State, t: jax.Array | None = None):
     # G_i = sum_a sum_p w^{a,p} x^{a,p}_i t^{a,p-1}_i (phantom w = 0)
     def accum_g(acc, xs):
         w_p, x_p, t_p = xs  # [A], [A, V], [A, V]
-        return acc + jnp.einsum("a,av,av->v", w_p, x_p, t_p), None
+        return acc + jnp.einsum(
+            "a,av,av->v", w_p, x_p, t_p, precision=HIGHEST
+        ), None
 
     G, _ = jax.lax.scan(
         accum_g,
@@ -195,7 +200,7 @@ def objective(
     *,
     solver: str = "neumann",
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """J(x, phi) plus a breakdown dict (Eq. 7 / the Fig-5 weighted variant)."""
     t = stage_traffic(

@@ -53,7 +53,7 @@ def forwarding_sweep(
     *,
     solver: str = "neumann",
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
     mass: jax.Array | None = None,
 ) -> State:
     """One full congestion-aware forwarding sweep (all apps/stages/nodes).
@@ -111,7 +111,7 @@ def forwarding_update(
     alpha: float = 0.5,
     solver: str = "neumann",
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> State:
     """T_phi inner forwarding sweeps (the paper's forwarding subproblem 8).
 

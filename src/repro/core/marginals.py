@@ -56,7 +56,7 @@ def cost_to_go(
     *,
     solver: str = "neumann",
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """Returns (q [A,K,V], dp [V,V], kappa [A,P,V], t [A,K,V], F, G)."""
     if t is None:
@@ -116,7 +116,7 @@ def round_eval(
     *,
     solver: str = "neumann",
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """One full marginal evaluation of `state`: (J, aux).
 
@@ -146,7 +146,7 @@ def link_marginals(
     *,
     solver: str = "neumann",
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """delta^{a,k}_{ij} (Eq. 10), BIG on non-edges. Returns (delta, aux)."""
     q, dp, kappa, t, F, G = cost_to_go(

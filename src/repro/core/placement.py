@@ -457,7 +457,7 @@ def placement_update(
     *,
     colocate: bool = False,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
     move_margin: float = 0.02,
     solver: str = "neumann",
     block_apps: int = 1,
@@ -494,7 +494,7 @@ def blocked_placement_update(
     *,
     colocate: bool = False,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
     move_margin: float = 0.02,
     solver: str = "neumann",
     block_apps: int = 1,
@@ -524,7 +524,7 @@ def blocked_sweep_cert(
     *,
     colocate: bool = False,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
     move_margin: float = 0.02,
     solver: str = "neumann",
     block_apps: int = 1,
@@ -592,7 +592,7 @@ def repair_placement(
     node_mask: jax.Array,
     *,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
     sp=None,
 ) -> State:
     """Evict partitions from masked-out hosts to the best live node.
@@ -705,7 +705,7 @@ def structured_init(
     *,
     colocate: bool = False,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
     sp=None,
 ) -> State:
     """Feasible structured initialization (paper section IV, method a).

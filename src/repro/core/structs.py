@@ -246,7 +246,7 @@ def hop_bound_cache(
     cache: HopBoundCache | None = None,
     *,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> HopBoundCache:
     """Compute (or incrementally refresh) the unweighted closure behind
     `infer_hop_bound`.
@@ -283,7 +283,7 @@ def infer_hop_bound(
     cache: HopBoundCache | None = None,
     *,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> int:
     """Unweighted graph diameter plus 2, covering one host re-injection per
     stage hand-off.

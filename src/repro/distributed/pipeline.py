@@ -80,22 +80,11 @@ def pipeline_apply(stage_fn, stage_params, x_microbatches, *, mesh: Mesh,
         return jax.lax.psum(outputs, axis)
 
     spec_params = jax.tree.map(lambda _: P(axis), stage_params)
-    if hasattr(jax, "shard_map"):  # jax >= 0.6 top-level API
-        fn = jax.shard_map(
-            per_stage,
-            mesh=mesh,
-            in_specs=(spec_params, P()),
-            out_specs=P(),
-            check_vma=False,
-        )
-    else:  # older releases: experimental namespace, check_rep kwarg
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        fn = _shard_map(
-            per_stage,
-            mesh=mesh,
-            in_specs=(spec_params, P()),
-            out_specs=P(),
-            check_rep=False,
-        )
+    fn = jax.shard_map(
+        per_stage,
+        mesh=mesh,
+        in_specs=(spec_params, P()),
+        out_specs=P(),
+        check_vma=False,
+    )
     return fn(stage_params, x_microbatches)

@@ -348,7 +348,7 @@ def _solve_fleet_stacked(
     patience: int,
     use_pallas: bool,
     solver: str,
-    interpret: bool = True,
+    interpret: bool = False,
     trace: bool = True,
     keep_state: bool = False,
     block_apps: int = 1,
@@ -546,7 +546,7 @@ def solve_fleet(
     shard: bool = False,
     devices: int | None = None,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
     solver: str = "neumann",
     block_apps: int = 1,
     lane_chunk: int | None = None,
@@ -591,8 +591,8 @@ def solve_fleet(
                  committed. Asking for a nonzero chunk together with
                  shard=True raises.
     interpret  : with use_pallas=True, run the kernel bodies under the Pallas
-                 interpreter (CPU validation). A real TPU/GPU launch passes
-                 interpret=False; ignored when use_pallas=False.
+                 interpreter (CPU tests only); ignored when
+                 use_pallas=False.
     chunk_size : split ensembles larger than this into fixed-B chunks that
                  share one global (V, A) envelope + hop bound, reusing a
                  single compiled program per (V, A, B) signature; the tail

@@ -29,9 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Renamed TPUCompilerParams -> CompilerParams across JAX releases.
-_COMPILER_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 NEG = -1e30
 M_INIT = -1e29
 
@@ -185,7 +182,7 @@ def flash_attention_pallas(
             pltpu.VMEM((bq_,), jnp.float32),
             pltpu.VMEM((bq_, d), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS_CLS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -26,7 +26,7 @@ def flash_attention(
     window: int | None = None,
     q_offset: int = 0,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     if use_pallas:
         return flash_attention_pallas(

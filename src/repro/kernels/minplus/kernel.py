@@ -4,17 +4,19 @@ TPU adaptation of the paper's Dijkstra-based placement step (DESIGN.md
 section 3): shortest paths under the marginal link weights D'_ij(F_ij) are
 computed by tropical matrix squaring. The (min,+) semiring has no MXU support
 (the systolic array is multiply-accumulate only), so the kernel targets the
-VPU: each grid step loads MXU-aligned 128x128 tiles of A and B into VMEM and
-reduces min over the K tile in KINNER-wide chunks, keeping the broadcast
-intermediate ([bm, KINNER, bn]) small enough to live comfortably in VMEM.
+VPU: each grid step loads MXU-aligned 128x128 tiles of A^T and B into VMEM
+and walks the K tile in KINNER-row chunks, each chunk a run of rank-1
+updates acc <- min(acc, a[:, k] + b[k, :]) on [bm, bn] vregs. A is passed
+transposed so that every dynamic slice runs along the sublane axis at a
+multiple of KINNER (Mosaic cannot slice the lane axis at such offsets).
 
 Grid: (M/bm, N/bn, K/bk) with K innermost ("arbitrary") so the output tile is
 revisited and used as the running-min accumulator — the standard Pallas
 matmul accumulation pattern, with (+, *) replaced by (min, +).
 
 VMEM budget per grid step (fp32, bm=bn=bk=128, KINNER=8):
-    A tile 64 KiB + B tile 64 KiB + out tile 64 KiB + broadcast 512 KiB
-    well under the ~16 MiB VMEM of a TPU core.
+    A tile 64 KiB + B tile 64 KiB + out tile 64 KiB + [bm, bn] accumulator
+    64 KiB, well under the ~16 MiB VMEM of a TPU core.
 """
 from __future__ import annotations
 
@@ -25,15 +27,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Renamed TPUCompilerParams -> CompilerParams across JAX releases.
-_COMPILER_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 BIG = 1e18
 DEFAULT_BLOCK = 128
 KINNER = 8
 
 
-def _minplus_kernel(a_ref, b_ref, o_ref, *, bk: int, k_steps: int):
+def _chunk(at_ref, b_ref, c):
+    """KINNER k-slices of one K tile: A columns [bm, KINNER], B rows [KINNER, bn].
+
+    Both slices run along the sublane axis (A arrives transposed), at an
+    offset that is a multiple of KINNER, which is what Mosaic can slice
+    dynamically; the [KINNER, bm] A slab is then transposed in VMEM.
+    """
+    k0 = pl.multiple_of(c * KINNER, KINNER)
+    a_cols = at_ref[pl.ds(k0, KINNER), :].T
+    b_rows = b_ref[pl.ds(k0, KINNER), :]
+    return k0, a_cols, b_rows
+
+
+def _minplus_kernel(at_ref, b_ref, o_ref, *, bk: int):
     """One (i, j, k) grid step: o[i,j] = min(o[i,j], min_k a[i,k]+b[k,j])."""
 
     # +inf (not BIG) is the accumulator identity: BIG-padding chunks then
@@ -43,29 +55,24 @@ def _minplus_kernel(a_ref, b_ref, o_ref, *, bk: int, k_steps: int):
     def _init():
         o_ref[...] = jnp.full_like(o_ref, jnp.inf)
 
-    a = a_ref[...]  # [bm, bk]
-    b = b_ref[...]  # [bk, bn]
-
     def body(c, acc):
-        # [bm, KINNER, 1] + [1, KINNER, bn] -> reduce min over KINNER.
-        a_chunk = jax.lax.dynamic_slice_in_dim(a, c * KINNER, KINNER, axis=1)
-        b_chunk = jax.lax.dynamic_slice_in_dim(b, c * KINNER, KINNER, axis=0)
-        cand = jnp.min(a_chunk[:, :, None] + b_chunk[None, :, :], axis=1)
-        return jnp.minimum(acc, cand)
+        _, a_cols, b_rows = _chunk(at_ref, b_ref, c)
+        for j in range(KINNER):  # rank-1 (min,+) updates, [bm, 1] + [1, bn]
+            acc = jnp.minimum(acc, a_cols[:, j : j + 1] + b_rows[j : j + 1, :])
+        return acc
 
-    acc = jnp.full_like(o_ref[...], jnp.inf)
+    acc = jnp.full(o_ref.shape, jnp.inf, jnp.float32)
     acc = jax.lax.fori_loop(0, bk // KINNER, body, acc)
     o_ref[...] = jnp.minimum(o_ref[...], acc)
 
 
-def _minplus_argmin_kernel(a_ref, b_ref, o_ref, ix_ref, *, bk: int):
+def _minplus_argmin_kernel(at_ref, b_ref, o_ref, ix_ref, *, bk: int):
     """Fused (min, argmin_k) grid step for the next-hop table.
 
     Tie-break contract: FIRST minimizing k, matching `jnp.argmin` on the
-    full candidate tensor. Within a chunk `jnp.argmin` already returns the
-    first minimum; across chunks and K tiles the strict `<` update keeps
-    the earliest, because k advances monotonically (K is the innermost
-    "arbitrary" grid dim and chunks walk the tile in order).
+    full candidate tensor. k advances monotonically (rank-1 updates in
+    order within a chunk, chunks in order within a tile, K the innermost
+    "arbitrary" grid dim), so the strict `<` update keeps the earliest.
     """
     kk = pl.program_id(2)
 
@@ -74,25 +81,43 @@ def _minplus_argmin_kernel(a_ref, b_ref, o_ref, ix_ref, *, bk: int):
         o_ref[...] = jnp.full_like(o_ref, jnp.inf)
         ix_ref[...] = jnp.zeros_like(ix_ref)
 
-    a = a_ref[...]  # [bm, bk]
-    b = b_ref[...]  # [bk, bn]
-
     def body(c, carry):
         acc, idx = carry
-        a_chunk = jax.lax.dynamic_slice_in_dim(a, c * KINNER, KINNER, axis=1)
-        b_chunk = jax.lax.dynamic_slice_in_dim(b, c * KINNER, KINNER, axis=0)
-        cand = a_chunk[:, :, None] + b_chunk[None, :, :]  # [bm, KINNER, bn]
-        cmin = jnp.min(cand, axis=1)
-        carg = jnp.argmin(cand, axis=1).astype(jnp.int32) + c * KINNER
-        upd = cmin < acc
-        return jnp.where(upd, cmin, acc), jnp.where(upd, carg, idx)
+        k0, a_cols, b_rows = _chunk(at_ref, b_ref, c)
+        for j in range(KINNER):
+            cand = a_cols[:, j : j + 1] + b_rows[j : j + 1, :]  # [bm, bn]
+            upd = cand < acc
+            acc = jnp.where(upd, cand, acc)
+            idx = jnp.where(upd, k0 + j, idx)
+        return acc, idx
 
-    acc = jnp.full_like(o_ref[...], jnp.inf)
-    idx = jnp.zeros_like(ix_ref[...])
+    acc = jnp.full(o_ref.shape, jnp.inf, jnp.float32)
+    idx = jnp.zeros(ix_ref.shape, jnp.int32)
     acc, idx = jax.lax.fori_loop(0, bk // KINNER, body, (acc, idx))
     upd = acc < o_ref[...]
     o_ref[...] = jnp.where(upd, acc, o_ref[...])
     ix_ref[...] = jnp.where(upd, idx + kk * bk, ix_ref[...])
+
+
+def _padded(a, b, block):
+    """BIG-pad to block multiples; A is returned transposed ([K, M])."""
+    (m, k), (k2, n) = a.shape, b.shape
+    assert k == k2, (a.shape, b.shape)
+    pad_m, pad_k, pad_n = (-m) % block, (-k) % block, (-n) % block
+    a_p = jnp.pad(a.astype(jnp.float32), ((0, pad_m), (0, pad_k)),
+                  constant_values=BIG)
+    b_p = jnp.pad(b.astype(jnp.float32), ((0, pad_k), (0, pad_n)),
+                  constant_values=BIG)
+    grid = ((m + pad_m) // block, (n + pad_n) // block, (k + pad_k) // block)
+    out_shape = (m + pad_m, n + pad_n)
+    return a_p.T, b_p, grid, out_shape
+
+
+def _specs(block):
+    return [
+        pl.BlockSpec((block, block), lambda i, j, kk: (kk, i)),
+        pl.BlockSpec((block, block), lambda i, j, kk: (kk, j)),
+    ]
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -108,33 +133,19 @@ def minplus_matmul_pallas(
     Inputs are padded with BIG (the (min,+) identity) to block multiples, so
     padding never affects the valid region.
     """
-    (m, k), (k2, n) = a.shape, b.shape
-    assert k == k2, (a.shape, b.shape)
-    a = a.astype(jnp.float32)
-    b = b.astype(jnp.float32)
-
-    pad_m = (-m) % block
-    pad_k = (-k) % block
-    pad_n = (-n) % block
-    a_p = jnp.pad(a, ((0, pad_m), (0, pad_k)), constant_values=BIG)
-    b_p = jnp.pad(b, ((0, pad_k), (0, pad_n)), constant_values=BIG)
-    mp, kp, np_ = m + pad_m, k + pad_k, n + pad_n
-
-    grid = (mp // block, np_ // block, kp // block)
+    m, n = a.shape[0], b.shape[1]
+    at_p, b_p, grid, out_shape = _padded(a, b, block)
     out = pl.pallas_call(
-        functools.partial(_minplus_kernel, bk=block, k_steps=grid[2]),
+        functools.partial(_minplus_kernel, bk=block),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((block, block), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((block, block), lambda i, j, kk: (kk, j)),
-        ],
+        in_specs=_specs(block),
         out_specs=pl.BlockSpec((block, block), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
-        compiler_params=_COMPILER_PARAMS_CLS(
+        out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(a_p, b_p)
+    )(at_p, b_p)
     return out[:m, :n]
 
 
@@ -149,42 +160,28 @@ def minplus_matmul_argmin_pallas(
     """Fused tropical matmul + argmin: (min_k A[i,k]+B[k,j], argmin_k ...).
 
     This is the next-hop table of `apsp_with_nexthop` computed tile-resident:
-    the [M, K, N] candidate tensor never exists, only [bm, KINNER, bn] chunks
-    in VMEM. Padding uses BIG so padded k indices lose every strict-< update
-    against real candidates (and on all-non-edge ties the first — real —
-    index wins, matching `jnp.argmin`).
+    the [M, K, N] candidate tensor never exists, only one [bm, bn]
+    candidate tile at a time in VMEM. Padding uses BIG so padded k indices
+    lose every strict-< update against real candidates (and on all-non-edge
+    ties the first — real — index wins, matching `jnp.argmin`).
     """
-    (m, k), (k2, n) = a.shape, b.shape
-    assert k == k2, (a.shape, b.shape)
-    a = a.astype(jnp.float32)
-    b = b.astype(jnp.float32)
-
-    pad_m = (-m) % block
-    pad_k = (-k) % block
-    pad_n = (-n) % block
-    a_p = jnp.pad(a, ((0, pad_m), (0, pad_k)), constant_values=BIG)
-    b_p = jnp.pad(b, ((0, pad_k), (0, pad_n)), constant_values=BIG)
-    mp, kp, np_ = m + pad_m, k + pad_k, n + pad_n
-
-    grid = (mp // block, np_ // block, kp // block)
+    m, n = a.shape[0], b.shape[1]
+    at_p, b_p, grid, out_shape = _padded(a, b, block)
     val, idx = pl.pallas_call(
         functools.partial(_minplus_argmin_kernel, bk=block),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((block, block), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((block, block), lambda i, j, kk: (kk, j)),
-        ],
+        in_specs=_specs(block),
         out_specs=[
             pl.BlockSpec((block, block), lambda i, j, kk: (i, j)),
             pl.BlockSpec((block, block), lambda i, j, kk: (i, j)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((mp, np_), jnp.float32),
-            jax.ShapeDtypeStruct((mp, np_), jnp.int32),
+            jax.ShapeDtypeStruct(out_shape, jnp.float32),
+            jax.ShapeDtypeStruct(out_shape, jnp.int32),
         ],
-        compiler_params=_COMPILER_PARAMS_CLS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(a_p, b_p)
+    )(at_p, b_p)
     return val[:m, :n], idx[:m, :n]

@@ -1,10 +1,10 @@
 """Jitted public wrappers around the min-plus kernel.
 
-`use_pallas` selects the Pallas kernel (TPU target; `interpret=True` executes
-the kernel body on CPU for validation). The default pure-jnp path is the
-k-blocked streaming matmul (peak memory O(V * block_k * V)), used by the CPU
-test/bench/dry-run flows; on a real TPU deployment the kernel path is enabled
-by one launch flag (`--use-pallas --no-interpret`, see launch/fleet.py).
+`use_pallas` selects the Pallas kernel, compiled for the TPU; CPU tests pass
+`interpret=True` to run the kernel body under the Pallas interpreter. The
+default pure-jnp path is the k-blocked streaming matmul (peak memory
+O(V * block_k * V)); a launch enables the kernel path with `--use-pallas`
+(see launch/fleet.py).
 
 APSP has two strategies:
 
@@ -38,7 +38,7 @@ BIG_THRESHOLD = 1e17
 _NEXTHOP_BLOCK_T = 128
 
 
-def minplus_matmul(a, b, *, use_pallas: bool = False, interpret: bool = True):
+def minplus_matmul(a, b, *, use_pallas: bool = False, interpret: bool = False):
     if use_pallas:
         return minplus_matmul_pallas(a, b, interpret=interpret)
     return minplus_matmul_blocked(a, b)
@@ -55,7 +55,7 @@ def minplus_closure(
     n_iter: int | None = None,
     early_exit: bool = True,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Close `d` to its transitive (min,+) fixpoint by repeated squaring.
 
@@ -114,7 +114,7 @@ def apsp(
     n_iter: int | None = None,
     early_exit: bool = True,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """All-pairs shortest-path distances.
 
@@ -177,7 +177,7 @@ def _nexthop_blocked(w: jax.Array, dist: jax.Array) -> jax.Array:
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
-def apsp_with_nexthop(w: jax.Array, *, use_pallas: bool = False, interpret: bool = True):
+def apsp_with_nexthop(w: jax.Array, *, use_pallas: bool = False, interpret: bool = False):
     """APSP distances + next-hop table.
 
     nexthop[i, t] = argmin_j  w[i, j] + dist[j, t]   (j over out-links of i)

@@ -39,10 +39,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Renamed TPUCompilerParams -> CompilerParams across JAX releases.
-_COMPILER_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 LANE = 128
+# fp32 products in every fp32 dot: the TPU's default contraction precision
+# may round fp32 operands to bf16. (Mosaic refuses it on bf16 operands, whose
+# products are exact anyway.)
+HIGHEST = jax.lax.Precision.HIGHEST
 MAX_VMEM_V = 1024
 # Contraction-axis tile of the K-tiled kernel: [block_k, Vp] W row tiles.
 # 512 keeps the streamed tile at Vp = 2048 under 4 MiB in fp32.
@@ -56,7 +57,9 @@ def _neumann_kernel(w_ref, b_ref, o_ref, *, hops: int, tol: float):
 
     def body(_, carry):
         x, done = carry
-        x_new = b + jnp.dot(x, w, preferred_element_type=jnp.float32)
+        x_new = b + jnp.dot(
+            x, w, precision=HIGHEST, preferred_element_type=jnp.float32
+        )
         resid = jnp.max(jnp.abs(x_new - x))
         scale = jnp.max(jnp.abs(x_new)) + 1e-30
         done_new = jnp.logical_or(done, resid <= tol * scale)
@@ -102,6 +105,7 @@ def _neumann_tiled_kernel(
     w_tile = w_ref[...]  # [bk, Vp], possibly bf16
     acc_ref[...] += jnp.dot(
         x_chunk.astype(w_tile.dtype), w_tile,
+        precision=HIGHEST if w_tile.dtype == jnp.float32 else None,
         preferred_element_type=jnp.float32,
     )
 
@@ -120,6 +124,16 @@ def _neumann_tiled_kernel(
     @pl.when(jnp.logical_and(h == hops - 1, kk == nk - 1))
     def _out():
         o_ref[...] = x_ref[...]
+
+
+def _rows(b: jax.Array, pad_v: int) -> jax.Array:
+    """[N, V] -> zero-padded [N, 1, Vp]: one row-vector block per grid step.
+
+    A [1, Vp] block of an [N, Vp] array is refused by Mosaic (the sublane
+    block dim must be a multiple of 8 or the whole axis); as the trailing
+    [1, Vp] of an [N, 1, Vp] array it is the whole of both minor axes.
+    """
+    return jnp.pad(b, ((0, 0), (0, pad_v)))[:, None, :]
 
 
 @functools.partial(
@@ -163,22 +177,22 @@ def neumann_solve_pallas(
         w = jnp.pad(
             jnp.swapaxes(m, -1, -2), ((0, 0), (0, pad_v), (0, pad_v))
         )
-        b_p = jnp.pad(b, ((0, 0), (0, pad_v)))
+        b_p = _rows(b, pad_v)
         out = pl.pallas_call(
             functools.partial(_neumann_kernel, hops=hops, tol=tol),
             grid=(n_batch,),
             in_specs=[
                 pl.BlockSpec((None, vp, vp), lambda i: (i, 0, 0)),
-                pl.BlockSpec((1, vp), lambda i: (i, 0)),
+                pl.BlockSpec((None, 1, vp), lambda i: (i, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, vp), lambda i: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((n_batch, vp), jnp.float32),
-            compiler_params=_COMPILER_PARAMS_CLS(
+            out_specs=pl.BlockSpec((None, 1, vp), lambda i: (i, 0, 0)),
+            out_shape=jax.ShapeDtypeStruct((n_batch, 1, vp), jnp.float32),
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",)
             ),
             interpret=interpret,
         )(w, b_p)
-        return out[:, :v]
+        return out[:, 0, :v]
 
     bk = DEFAULT_BLOCK_K if block_k is None else int(block_k)
     if bk % LANE:
@@ -190,7 +204,7 @@ def neumann_solve_pallas(
     w = jnp.pad(jnp.swapaxes(m, -1, -2), ((0, 0), (0, pad_v), (0, pad_v)))
     if operand_dtype is not None:
         w = w.astype(operand_dtype)
-    b_p = jnp.pad(b, ((0, 0), (0, pad_v)))
+    b_p = _rows(b, pad_v)
 
     out = pl.pallas_call(
         functools.partial(
@@ -199,18 +213,18 @@ def neumann_solve_pallas(
         grid=(n_batch, hops, nk),
         in_specs=[
             pl.BlockSpec((None, bk, vp), lambda i, h, k: (i, k, 0)),
-            pl.BlockSpec((1, vp), lambda i, h, k: (i, 0)),
+            pl.BlockSpec((None, 1, vp), lambda i, h, k: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, vp), lambda i, h, k: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_batch, vp), jnp.float32),
+        out_specs=pl.BlockSpec((None, 1, vp), lambda i, h, k: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_batch, 1, vp), jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((1, vp), jnp.float32),
             pltpu.VMEM((1, vp), jnp.float32),
             pltpu.SMEM((1,), jnp.int32),
         ],
-        compiler_params=_COMPILER_PARAMS_CLS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")
         ),
         interpret=interpret,
     )(w, b_p)
-    return out[:, :v]
+    return out[:, 0, :v]

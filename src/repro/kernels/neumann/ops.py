@@ -23,7 +23,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .kernel import neumann_solve_pallas
+from .kernel import HIGHEST, neumann_solve_pallas
 
 # Extra hops past the nilpotent bound, absorbing the geometric tail of
 # blocking-rule transient cycles (gain <= 1 - alpha per sweep; see
@@ -66,8 +66,13 @@ def effective_hops(
 
 
 def _bmv(m: jax.Array, x: jax.Array) -> jax.Array:
-    """Batched matvec (m x) over arbitrary shared leading dims."""
-    return jnp.einsum("...ij,...j->...i", m, x)
+    """Batched matvec (m x) over arbitrary shared leading dims.
+
+    HIGHEST keeps the products fp32 on the TPU, where XLA's default rounds
+    dot operands to bf16 and would move the fixed point by ~1e-3 relative;
+    the matvec is memory-bound, so the extra MXU passes are free.
+    """
+    return jnp.einsum("...ij,...j->...i", m, x, precision=HIGHEST)
 
 
 def _propagate_xla(m: jax.Array, b: jax.Array, hops: int, tol: float) -> jax.Array:
@@ -123,7 +128,7 @@ def neumann_solve(
     hops: int,
     tol: float = DEFAULT_TOL,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
     block_k: int | None = None,
     operand_dtype=None,
 ) -> jax.Array:

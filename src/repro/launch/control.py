@@ -13,7 +13,8 @@ Per epoch:
      since their last solve burn rounds (`solve_fleet(warm_start=...,
      warm_active=changed)`); an event-free epoch costs one init eval.
 
-Degradation ladder on non-finite J, infeasible placement, or an exception:
+Degradation ladder on non-finite J, infeasible placement, or an exception
+(each exception is also counted in the epoch's `solve_errors`):
 warm -> cold re-solve from scratch -> CoLocated (the always-feasible
 single-host baseline) -> carry the repaired previous placement unchanged.
 Escalation honors a soft per-epoch timeout and optional exponential
@@ -53,6 +54,7 @@ from repro.fleet.pad import (
     fleet_part_envelope,
     unify_hop_bound,
 )
+from repro.launch.compile_cache import configure_compile_cache
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.obs.trace import span
@@ -76,6 +78,9 @@ class EpochReport:
                controller ran one (compare_cold; event epochs only)
     recovery_s : wall time from epoch start to accepted placement, only for
                epochs where at least one fault/recovery fired
+    solve_errors : ladder rungs whose solve raised (an exception, not an
+               infeasible or non-finite result) — a fault of the program,
+               which the ladder absorbs but must not hide
     """
 
     epoch: int
@@ -91,6 +96,7 @@ class EpochReport:
     wall_s: float
     recovery_s: float | None = None
     cold_rounds: int | None = None
+    solve_errors: int = 0
 
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -125,6 +131,7 @@ class ControlResult:
             ),
             "infeasible_epochs": sum(not r.feasible for r in self.reports),
             "nonfinite_epochs": sum(not r.finite for r in self.reports),
+            "solve_errors": sum(r.solve_errors for r in self.reports),
             "fallback_epochs": sum(
                 r.outcome != "ok" for r in self.reports
             ),
@@ -181,7 +188,7 @@ def run_control(
     patience: int = 4,
     solver: str = "neumann",
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
     block_apps: int = 1,
     lane_chunk: int | None = None,
     round_to: int = 8,
@@ -200,7 +207,7 @@ def run_control(
     trace        : a pre-generated `FaultTrace`; None generates one from
                    (fleet, epochs, seed, **trace_kwargs)
     interpret    : with use_pallas, run kernel bodies under the Pallas
-                   interpreter (CPU validation; --no-interpret on real TPU)
+                   interpreter (CPU tests only)
     timeout_s    : soft per-epoch budget — once exceeded, the ladder stops
                    escalating and carries the repaired placement
     backoff_s    : base of the exponential retry backoff between rungs
@@ -364,6 +371,7 @@ def run_control(
             result = None
             accepted_rung = None
             attempts = 0
+            solve_errors = 0
             for rung, (name, extra) in enumerate(ladder):
                 if (
                     attempts > 0
@@ -386,6 +394,8 @@ def run_control(
                     logger.exception(
                         "control: epoch %d %s solve raised", epoch, name
                     )
+                    solve_errors += 1
+                    reg.counter("control.solve_errors").inc()
                     continue
                 if not np.isfinite(r.J).all():
                     logger.warning(
@@ -461,6 +471,7 @@ def run_control(
                 wall_s=round(wall, 4),
                 recovery_s=round(wall, 4) if fired else None,
                 cold_rounds=cold_rounds,
+                solve_errors=solve_errors,
             )
             reports.append(report)
             prev_health = list(healths)
@@ -509,9 +520,9 @@ def main(argv=None) -> int:
         "Pallas kernels instead of the pure-XLA paths",
     )
     ap.add_argument(
-        "--interpret", action=argparse.BooleanOptionalAction, default=True,
+        "--interpret", action=argparse.BooleanOptionalAction, default=False,
         help="with --use-pallas, run kernel bodies under the Pallas "
-        "interpreter (a real TPU/GPU launch passes --no-interpret)",
+        "interpreter (CPU tests only; off by default)",
     )
     ap.add_argument(
         "--verify-hop-bound", action="store_true",
@@ -558,7 +569,8 @@ def main(argv=None) -> int:
     ap.add_argument("--trace-out", default=None, help="host span trace JSONL")
     ap.add_argument(
         "--assert-feasible", action="store_true",
-        help="exit nonzero unless every epoch was feasible with finite J",
+        help="exit nonzero unless every epoch was feasible with finite J "
+        "and no solve raised",
     )
     args = ap.parse_args(argv)
 
@@ -621,11 +633,12 @@ def main(argv=None) -> int:
             )
     obs_trace.flush()
     if args.assert_feasible and (
-        s["infeasible_epochs"] or s["nonfinite_epochs"]
+        s["infeasible_epochs"] or s["nonfinite_epochs"] or s["solve_errors"]
     ):
         print(
             f"ASSERTION FAILED: {s['infeasible_epochs']} infeasible / "
-            f"{s['nonfinite_epochs']} non-finite epochs",
+            f"{s['nonfinite_epochs']} non-finite epochs, "
+            f"{s['solve_errors']} solves raised",
             flush=True,
         )
         return 1
@@ -633,4 +646,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     raise SystemExit(main())

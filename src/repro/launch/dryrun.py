@@ -1,6 +1,15 @@
 import os
+import sys
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# The 512-device CPU mesh must be requested before jax initialises its
+# backend. Append to the caller's XLA_FLAGS, and leave them alone when jax
+# is already imported: a late flag change would silently not apply.
+if "jax" not in sys.modules:
+    _flags = os.environ.get("XLA_FLAGS", "")
+    if "xla_force_host_platform_device_count" not in _flags:
+        os.environ["XLA_FLAGS"] = (
+            _flags + " --xla_force_host_platform_device_count=512"
+        ).strip()
 
 """Multi-pod dry-run: prove the distribution config is coherent without
 hardware.
@@ -207,12 +216,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
             compiled = lowered.compile()
             t_compile = time.time() - t0 - t_lower
 
-            # cost_analysis() returns a dict on recent JAX, a 1-element list
-            # of dicts on older releases; accept both.
-            ca = compiled.cost_analysis() or {}
-            if isinstance(ca, (list, tuple)):
-                ca = ca[0] if ca else {}
-            cost = dict(ca)
+            cost = dict(compiled.cost_analysis() or {})
             cost = {k: float(v) for k, v in cost.items() if np.isscalar(v)}
             try:
                 ma = compiled.memory_analysis()

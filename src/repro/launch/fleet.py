@@ -26,6 +26,7 @@ import time
 
 from repro.core import SCENARIOS
 from repro.fleet import FAMILIES, load_grid, sample_fleet, solve_fleet
+from repro.launch.compile_cache import configure_compile_cache
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 
@@ -101,10 +102,9 @@ def main(argv=None) -> int:
     ap.add_argument(
         "--interpret",
         action=argparse.BooleanOptionalAction,
-        default=True,
+        default=False,
         help="with --use-pallas, run the kernel bodies under the Pallas "
-        "interpreter (CPU validation). A real TPU/GPU launch passes "
-        "--use-pallas --no-interpret; no effect without --use-pallas",
+        "interpreter (CPU tests only; off by default)",
     )
     ap.add_argument(
         "--chunk-size",
@@ -229,4 +229,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     raise SystemExit(main())

@@ -27,6 +27,7 @@ import time
 
 from repro.configs import ZOO
 from repro.core import SCENARIOS
+from repro.launch.compile_cache import configure_compile_cache
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.partition.pareto import check_fronts, sweep_zoo
@@ -138,7 +139,9 @@ def main(argv=None) -> int:
     )
     ap.add_argument("--use-pallas", action="store_true")
     ap.add_argument(
-        "--interpret", action=argparse.BooleanOptionalAction, default=True
+        "--interpret", action=argparse.BooleanOptionalAction, default=False,
+        help="with --use-pallas, run kernel bodies under the Pallas "
+        "interpreter (CPU tests only; off by default)",
     )
     ap.add_argument(
         "--assert-front",
@@ -219,4 +222,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     raise SystemExit(main())

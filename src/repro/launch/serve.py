@@ -30,6 +30,7 @@ import numpy as np
 
 from repro.configs import get_config, reduced_config
 from repro.models import decode_step, init_caches, init_params, prefill
+from repro.launch.compile_cache import configure_compile_cache
 from repro.launch.steps import serve_config
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
@@ -212,4 +213,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     raise SystemExit(main())

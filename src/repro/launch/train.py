@@ -35,16 +35,27 @@ from repro.distributed.sharding import (
     param_pspecs,
     to_named_shardings,
 )
+from repro.launch.compile_cache import configure_compile_cache
 from repro.launch import steps as St
 from repro.models import init_params
 from repro.optim import adamw_init
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 
 def build_mesh(model_parallel: int):
+    """(data, model) mesh with automatic axes.
+
+    The model lays out activations by sharding constraints and leaves the
+    rest to the partitioner, so its axes are Auto: under the Explicit axes
+    that `jax.make_mesh` gives by default, every gather from the sharded
+    embedding and every contraction over a sharded dim would need its
+    output sharding spelled out.
+    """
     n = len(jax.devices())
     mp = max(1, min(model_parallel, n))
-    return jax.make_mesh((n // mp, mp), ("data", "model"))
+    return jax.make_mesh(
+        (n // mp, mp), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+    )
 
 
 def main(argv=None):
@@ -159,4 +170,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     raise SystemExit(main())

@@ -47,7 +47,7 @@ def profile_round_phases(
     alpha: float = 0.5,
     colocate: bool = False,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
     solver: str = "neumann",
     block_apps: int = 1,
     round_to: int = 1,

@@ -121,7 +121,7 @@ def sweep_zoo(
     chunk_size=None,
     envelope_cap_gb=2.0,
     use_pallas=False,
-    interpret=True,
+    interpret=False,
     solver="neumann",
     validate=True,
 ) -> dict:

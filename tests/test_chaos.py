@@ -334,12 +334,62 @@ def test_controller_every_epoch_servable():
     assert s["epochs"] == 6
     assert s["feasible_fraction"] == 1.0
     assert s["nonfinite_epochs"] == 0
+    assert s["solve_errors"] == 0
     # epoch 0 is the cold bootstrap; later epochs warm-start
     assert ctl.reports[0].mode == "cold"
     assert all(r.mode == "warm" for r in ctl.reports[1:])
     # event-free epochs freeze the whole batch: zero engine trips
     quiet = [r for r in ctl.reports[1:] if r.perturbed == 0]
     assert all(r.rounds == 0 for r in quiet)
+
+
+def _fail_first_solve(monkeypatch):
+    """Make the controller's first `solve_fleet` call raise."""
+    from repro.launch import control
+
+    real = control.solve_fleet
+    calls = []
+
+    def flaky(probs, **kw):
+        calls.append(kw.get("method"))
+        if len(calls) == 1:
+            raise RuntimeError("injected solve fault")
+        return real(probs, **kw)
+
+    monkeypatch.setattr(control, "solve_fleet", flaky)
+    return calls
+
+
+def test_controller_counts_raised_solves(monkeypatch):
+    """A solve that raises is absorbed by the ladder (the next rung serves
+    the epoch) but counted in the epoch's report and the summary."""
+    from repro.launch.control import run_control
+
+    calls = _fail_first_solve(monkeypatch)
+    ctl = run_control(
+        _small_fleet(2, seed=31), epochs=4, seed=13, m_max=2, t_phi=2,
+        round_to=8,
+        trace_kwargs=dict(node_failures=1, link_degradations=0, flash_crowds=0),
+    )
+    assert calls[:2] == ["ALT", "CoLocated"]  # epoch 0: cold raised
+    assert [r.solve_errors for r in ctl.reports] == [1, 0, 0, 0]
+    assert ctl.reports[0].outcome == "colocated"
+    s = ctl.summary()
+    assert s["solve_errors"] == 1
+    assert s["feasible_fraction"] == 1.0
+
+
+def test_controller_cli_fails_on_raised_solve(monkeypatch):
+    from repro.launch.control import main
+
+    _fail_first_solve(monkeypatch)
+    rc = main([
+        "--instances", "2", "--epochs", "4", "--seed", "4",
+        "--node-failures", "0", "--link-degradations", "0",
+        "--flash-crowds", "0", "--m-max", "2", "--t-phi", "2",
+        "--assert-feasible",
+    ])
+    assert rc == 1
 
 
 def test_controller_cli_smoke(tmp_path):
@@ -357,6 +407,7 @@ def test_controller_cli_smoke(tmp_path):
     assert rc == 0
     payload = json.loads(out.read_text())
     assert payload["summary"]["feasible_fraction"] == 1.0
+    assert payload["summary"]["solve_errors"] == 0
     assert len(payload["epochs"]) == 5
     sched = json.loads(events.read_text())
     assert sched["counts"]["node_down"] == 1

@@ -4,6 +4,7 @@ import dataclasses
 import os
 import subprocess
 import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +17,8 @@ from repro.data import DataConfig, make_pipeline
 from repro.optim import adamw_init, adamw_update, clip_by_global_norm, cosine_schedule
 from repro.optim.compression import compress_int8, decompress_int8
 from repro.models.quant import dequantize_leaf, quantize_leaf, quantize_params
+
+REPO = Path(__file__).resolve().parents[1]
 
 
 # ---------------------------------------------------------------------------
@@ -227,12 +230,12 @@ def test_train_loop_resume(tmp_path):
     ]
     r1 = subprocess.run(
         base + ["--steps", "8"], env=env, capture_output=True, text=True,
-        timeout=600, cwd="/root/repo",
+        timeout=600, cwd=str(REPO),
     )
     assert r1.returncode == 0, r1.stderr[-2000:]
     r2 = subprocess.run(
         base + ["--steps", "12", "--resume"], env=env, capture_output=True,
-        text=True, timeout=600, cwd="/root/repo",
+        text=True, timeout=600, cwd=str(REPO),
     )
     assert r2.returncode == 0, r2.stderr[-2000:]
     assert "resumed from step 8" in r2.stdout
