@@ -1,0 +1,8 @@
+"""Busy device time under the engine's `alt.placement` scope (the placement
+sweep), per instance-round of a profiled call, in ms."""
+from harness.scopes import per_inst_round
+
+
+def read(reading):
+    v = per_inst_round(reading, "busy_s", "alt.placement")
+    return None if v is None else 1e3 * v

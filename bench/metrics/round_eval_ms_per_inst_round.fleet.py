@@ -1,0 +1,9 @@
+"""Busy device time under the engine's `alt.round_eval` scope (loads,
+objective and cost-to-go, their Neumann solves included), per
+instance-round of a profiled call, in ms."""
+from harness.scopes import per_inst_round
+
+
+def read(reading):
+    v = per_inst_round(reading, "busy_s", "alt.round_eval")
+    return None if v is None else 1e3 * v

@@ -47,12 +47,7 @@ per-round wall time with tracing on vs off (interleaved best-of-N) and
 asserts the traced solve stays within 5% of the untraced one, plus bitwise
 identity of every solved output across the two settings.
 
-Section 10 (phases): the per-phase round profile (`obs.profile_round_phases`)
-over the section-1 fleet — placement sweep vs T_phi forwarding sweeps vs
-round_eval, persisted so BENCH_fleet.json records where the round budget
-actually goes (placement is a few percent; forwarding dominates).
-
-`REPRO_FLEET_SECTIONS=engine,phases` (comma list of section names) runs a
+`REPRO_FLEET_SECTIONS=engine,obs` (comma list of section names) runs a
 subset; skipped sections are recorded as `{"skipped": true}` so
 `benchmarks/run.py --check-trend` can refuse a partial baseline.
 
@@ -573,33 +568,6 @@ def _bench_pareto(print_fn) -> dict:
     )
 
 
-def _bench_phases(print_fn) -> dict:
-    """Section 10: per-phase round profile over the section-1 fleet.
-
-    Persists where one engine round's budget actually goes. The measured
-    split (placement a few percent, forwarding dominant) is the datum
-    behind the lane-chunk layout decision in DESIGN.md section 18 — keep it
-    in BENCH_fleet.json so a future shift (e.g. a placement regression
-    making the sweep dominant again) is visible in the trend."""
-    from repro.obs import profile_round_phases
-
-    fleet = sample_fleet(BATCH, **ENGINE_FLEET_KW)
-    prof = profile_round_phases(
-        fleet, t_phi=SOLVE_KW["t_phi"], reps=WARM_REPS, **pallas_knobs()
-    )
-    prof["placement_sweep_ms"] = prof["placement_ms"]
-    print_fn(
-        f"fleet,phases B={prof['batch']} t_phi={prof['t_phi']} "
-        f"placement={prof['placement_ms']:.1f}ms "
-        f"({prof['placement_share']:.1%}) "
-        f"forwarding={prof['forwarding_ms']:.1f}ms "
-        f"({prof['forwarding_share']:.1%}) "
-        f"round_eval={prof['round_eval_ms']:.1f}ms "
-        f"({prof['round_eval_share']:.1%})"
-    )
-    return prof
-
-
 SECTIONS_ENV = "REPRO_FLEET_SECTIONS"
 
 
@@ -614,7 +582,6 @@ def run(print_fn=print, solver: str = "neumann") -> dict:
         "obs": lambda: _bench_obs(print_fn),
         "chaos": lambda: _bench_chaos(print_fn),
         "pareto": lambda: _bench_pareto(print_fn),
-        "phases": lambda: _bench_phases(print_fn),
     }
     requested = os.environ.get(SECTIONS_ENV)
     if requested:

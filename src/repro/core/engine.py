@@ -53,6 +53,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..obs.trace import engine_scope
 from .forwarding import forwarding_update
 from .marginals import round_eval
 from .placement import placement_update, structured_init
@@ -204,17 +205,21 @@ def round_step(
     """
 
     def one_round(p, s, ctg):
-        nxt = placement_update(
-            p, s, ctg, colocate=colocate, use_pallas=use_pallas,
-            interpret=interpret, solver=solver, block_apps=block_apps,
-        )
-        nxt = forwarding_update(
-            p, nxt, t_phi=t_phi, alpha=alpha, solver=solver,
-            use_pallas=use_pallas, interpret=interpret,
-        )
-        J, aux_nxt = round_eval(
-            p, nxt, solver=solver, use_pallas=use_pallas, interpret=interpret
-        )
+        with engine_scope("alt.placement"):
+            nxt = placement_update(
+                p, s, ctg, colocate=colocate, use_pallas=use_pallas,
+                interpret=interpret, solver=solver, block_apps=block_apps,
+            )
+        with engine_scope("alt.forwarding"):
+            nxt = forwarding_update(
+                p, nxt, t_phi=t_phi, alpha=alpha, solver=solver,
+                use_pallas=use_pallas, interpret=interpret,
+            )
+        with engine_scope("alt.round_eval"):
+            J, aux_nxt = round_eval(
+                p, nxt, solver=solver, use_pallas=use_pallas,
+                interpret=interpret,
+            )
         return nxt, J, aux_nxt
 
     nxt, J, aux_nxt = jax.vmap(one_round)(
@@ -239,22 +244,23 @@ def round_step(
         # inherits the freeze-point contract bit for bit. Everything here is
         # computed from values the round already produced — no extra solves,
         # no host syncs, and the main dataflow never reads a trace buffer.
-        moved = placement_churn(problem, nxt, carry.state)
-        trace = EngineTrace(
-            J_comm=trace.J_comm.at[:, col].set(
-                jnp.where(active, aux_nxt["J_comm"], jnp.nan)
-            ),
-            J_comp=trace.J_comp.at[:, col].set(
-                jnp.where(active, aux_nxt["J_comp"], jnp.nan)
-            ),
-            moves=trace.moves.at[:, col].set(
-                jnp.where(active, moved.astype(trace.moves.dtype), jnp.nan)
-            ),
-            live=trace.live.at[:, col].set(active.astype(trace.live.dtype)),
-            best_round=jnp.where(
-                active & is_best, col.astype(jnp.int32), trace.best_round
-            ),
-        )
+        with engine_scope("alt.roundtrace"):
+            moved = placement_churn(problem, nxt, carry.state)
+            trace = EngineTrace(
+                J_comm=trace.J_comm.at[:, col].set(
+                    jnp.where(active, aux_nxt["J_comm"], jnp.nan)
+                ),
+                J_comp=trace.J_comp.at[:, col].set(
+                    jnp.where(active, aux_nxt["J_comp"], jnp.nan)
+                ),
+                moves=trace.moves.at[:, col].set(
+                    jnp.where(active, moved.astype(trace.moves.dtype), jnp.nan)
+                ),
+                live=trace.live.at[:, col].set(active.astype(trace.live.dtype)),
+                best_round=jnp.where(
+                    active & is_best, col.astype(jnp.int32), trace.best_round
+                ),
+            )
     active_nxt = active & (stall_nxt < patience)
     return EngineCarry(
         state=_bwhere(active, nxt, carry.state),
@@ -307,14 +313,17 @@ def _engine_solve_batch(
             )
             return s, J, aux
 
-        state0, J0, aux0 = jax.vmap(init_one)(stacked)
+        with engine_scope("alt.init"):
+            state0, J0, aux0 = jax.vmap(init_one)(stacked)
     else:
         state0 = init_state
-        J0, aux0 = jax.vmap(
-            lambda p, s: round_eval(
-                p, s, solver=solver, use_pallas=use_pallas, interpret=interpret
-            )
-        )(stacked, state0)
+        with engine_scope("alt.init"):
+            J0, aux0 = jax.vmap(
+                lambda p, s: round_eval(
+                    p, s, solver=solver, use_pallas=use_pallas,
+                    interpret=interpret,
+                )
+            )(stacked, state0)
     batch = J0.shape[0]
     history0 = jnp.full((batch, m_max + 1), jnp.nan, dtype=J0.dtype)
     trace0 = None
@@ -358,8 +367,13 @@ def _engine_solve_batch(
         interpret=interpret,
         block_apps=block_apps,
     )
+
+    def body(c):
+        with engine_scope("alt.round"):
+            return step(c)
+
     carry = jax.lax.while_loop(
-        lambda c: (c.m < m_max) & c.any_active, step, carry
+        lambda c: (c.m < m_max) & c.any_active, body, carry
     )
     if track_best:
         out_state, out_obj = carry.best_state, carry.best_obj
@@ -466,9 +480,10 @@ def engine_solve(
         block_apps=block_apps, keep_state=keep_state,
     )
     if lane_chunk == 0:
-        return _engine_solve_batch(
-            stacked, init_state=init_state, active0=active0, **kw
-        )
+        with engine_scope("alt.engine"):
+            return _engine_solve_batch(
+                stacked, init_state=init_state, active0=active0, **kw
+            )
 
     def lane_solve(args):
         p, s0, a0 = args
@@ -493,14 +508,16 @@ def engine_solve(
         squeezed["rounds"] = out["rounds"]
         return squeezed
 
-    out = jax.lax.map(
-        lane_solve,
-        (stacked, init_state, active0),
-        batch_size=lane_chunk if lane_chunk > 1 else None,
-    )
-    # Per-lane loop trips stack to [B]; the engine contract is ONE scalar
-    # (trips the batch would have executed in lockstep = the slowest lane).
-    out["rounds"] = jnp.max(out["rounds"])
+    with engine_scope("alt.engine"):
+        out = jax.lax.map(
+            lane_solve,
+            (stacked, init_state, active0),
+            batch_size=lane_chunk if lane_chunk > 1 else None,
+        )
+        # Per-lane loop trips stack to [B]; the engine contract is ONE
+        # scalar (trips the batch would have executed in lockstep = the
+        # slowest lane).
+        out["rounds"] = jnp.max(out["rounds"])
     return out
 
 

@@ -42,7 +42,8 @@ Observability (DESIGN.md section 14): `trace=True` (default) carries the
 engine's on-device round trace through the gather as `FleetResult.trace`
 (per-round J split, placement churn, live mask, best-round index — same
 NaN-past-freeze contract as `history`); the host-side stack/commit/execute/
-gather boundaries are bracketed by `obs.trace` spans, and per-solve
+gather boundaries are bracketed by `obs.trace` spans (execute split into
+dispatch and, when tracing, the wait for the device), and per-solve
 telemetry (chunks, pad overhead, rounds vs budget, warm/cold compiles)
 lands in `obs.metrics.registry`.
 """
@@ -494,13 +495,16 @@ def _run_chunk(
         "fleet.compile.cold" if cold else "fleet.compile.warm"
     ).inc()
     with span("solve_fleet.execute", batch=target, cold_compile=cold):
-        out = _solve_fleet_stacked(
-            stacked, init_state=init_state, active0=active0, **solve_kw
-        )
+        # dispatch: argument handling, input transfer and enqueue
+        with span("solve_fleet.dispatch"):
+            out = _solve_fleet_stacked(
+                stacked, init_state=init_state, active0=active0, **solve_kw
+            )
         if tracer_enabled():
             # Only when tracing: make the span cover the device work, not
             # just the dispatch. Untraced solves keep async dispatch.
-            jax.block_until_ready(out["J"])
+            with span("solve_fleet.wait"):
+                jax.block_until_ready(out["J"])
     out["parts"] = stacked.apps.parts
     sharded_out = mesh is not None and carries_fleet_sharding(out["J"])
     if mesh is not None and not sharded_out:

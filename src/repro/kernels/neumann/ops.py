@@ -23,6 +23,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ...obs.trace import engine_scope
 from .kernel import HIGHEST, neumann_solve_pallas
 
 # Extra hops past the nilpotent bound, absorbing the geometric tail of
@@ -152,10 +153,11 @@ def neumann_solve(
             )
         return _propagate_xla(op, rhs, hops, tol)
 
-    mt = jnp.swapaxes(m, -1, -2)
-    return jax.lax.custom_linear_solve(
-        lambda x: x - _bmv(m, x),
-        b,
-        solve=lambda _, rhs: run(m, rhs),
-        transpose_solve=lambda _, rhs: run(mt, rhs),
-    )
+    with engine_scope("neumann"):
+        mt = jnp.swapaxes(m, -1, -2)
+        return jax.lax.custom_linear_solve(
+            lambda x: x - _bmv(m, x),
+            b,
+            solve=lambda _, rhs: run(m, rhs),
+            transpose_solve=lambda _, rhs: run(mt, rhs),
+        )

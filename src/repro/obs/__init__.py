@@ -18,9 +18,17 @@ Three layers (DESIGN.md section 14):
      counters/gauges/histograms (chunks executed, pad overhead, rounds vs
      budget, compile warm/cold, serve latencies) snapshotted into the
      launch CLIs' JSON and `benchmarks/run.py --json-out`.
+
+Inside the compiled engine, `obs.trace.engine_scope` names its layers
+(`ENGINE_SCOPES`: alt.engine, alt.init, alt.round, alt.placement,
+alt.forwarding, alt.round_eval, alt.roundtrace, neumann) as
+`jax.named_scope` metadata, which adds no operation. The chip benchmark
+splits the engine's device time by them (`bench/harness/scopes.py`):
+`placement_ms_per_inst_round.fleet`, `forwarding_ms_per_inst_round.fleet`,
+`round_eval_ms_per_inst_round.fleet`, `neumann_ms_per_inst_round.fleet`,
+`engine_gap_ms_per_inst_round.fleet` and `engine_ops_per_inst_round.fleet`.
 """
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry, registry  # noqa: F401
-from .phases import profile_round_phases  # noqa: F401
 from .roundtrace import FleetTrace  # noqa: F401
 from .trace import (  # noqa: F401
     TRACER,

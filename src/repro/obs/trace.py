@@ -28,6 +28,12 @@ independently. Events are recorded at span *exit* (a parent's duration is
 unknown while its children run), which means children precede their parent
 in the stream — consumers join on the explicit `parent` id rather than
 stream order; `repro.obs.validate` checks that containment.
+
+Device work carries names of its own: `engine_scope(name)` wraps a layer of
+the ALT engine in a `jax.named_scope` from `ENGINE_SCOPES`. A named scope is
+compile-time metadata only (the `op_name` of each HLO instruction): it adds
+no operation and no cost, traced or not, and the benchmark's trace reduction
+splits the engine program's device time by it.
 """
 from __future__ import annotations
 
@@ -42,6 +48,37 @@ import time
 
 TRACE_ENV = "REPRO_TRACE"
 JAX_TRACE_ENV = "REPRO_JAX_TRACE"
+
+# The ALT engine's layers inside its compiled program, outermost first:
+#   alt.engine      all of `engine_solve`; ops directly under it are the
+#                   lane map and loop control
+#   alt.init        structured init and the round-0 evaluation
+#   alt.round       one round's freeze / stall / best-iterate bookkeeping
+#   alt.placement   the placement sweep
+#   alt.forwarding  the T_phi forwarding sweeps
+#   alt.round_eval  loads, objective and cost-to-go
+#   alt.roundtrace  the on-device round trace (`EngineTrace` writes, churn)
+#   neumann         the propagation solve, nested under the layer calling it
+ENGINE_SCOPES = (
+    "alt.engine",
+    "alt.init",
+    "alt.round",
+    "alt.placement",
+    "alt.forwarding",
+    "alt.round_eval",
+    "alt.roundtrace",
+    "neumann",
+)
+
+
+def engine_scope(name: str):
+    """`jax.named_scope(name)` for one of `ENGINE_SCOPES`; any other name
+    raises, so the program and the trace reduction cannot drift apart."""
+    if name not in ENGINE_SCOPES:
+        raise ValueError(f"{name!r} is not one of ENGINE_SCOPES {ENGINE_SCOPES}")
+    import jax
+
+    return jax.named_scope(name)
 
 
 @dataclasses.dataclass(frozen=True)

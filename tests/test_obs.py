@@ -13,6 +13,7 @@ Three layers, three contracts:
     errors, histogram percentiles, snapshot shape.
 """
 import json
+import re
 
 import jax
 import numpy as np
@@ -169,6 +170,84 @@ class TestEngineTrace:
         np.testing.assert_allclose(ts.J_comp, tu.J_comp, rtol=1e-5)
 
 
+def _canonical_hlo(text):
+    """Compiled HLO text without metadata, the stack-frame tables, and
+    with instruction names numbered by first appearance (some names follow
+    the name stack)."""
+    text = re.sub(r', metadata=\{(?:[^{}"]|"(?:[^"\\]|\\.)*")*\}', "", text)
+    text = re.sub(
+        r"^(FileNames|FunctionNames|FileLocations|StackFrames)\n.*?\n\n", "",
+        text, flags=re.S | re.M,
+    )
+    names = {}
+    return re.sub(
+        r"%[\w.\-]+", lambda m: names.setdefault(m.group(0), f"%n{len(names)}"),
+        text,
+    )
+
+
+def _engine_hlo(stacked, **kw):
+    return engine_solve.lower(stacked, **kw).compile().as_text()
+
+
+class TestEngineScopes:
+    """The engine's named scopes (`obs.trace.ENGINE_SCOPES`) are compile-time
+    metadata: every layer is tagged, and nothing else changes."""
+
+    KW = dict(m_max=3, t_phi=2, alpha=0.5, tol=1e-3, patience=2, trace=True)
+
+    @pytest.fixture(scope="class")
+    def stacked(self):
+        return stack_problems(sample_fleet(2, seed=21))[0]
+
+    @pytest.mark.parametrize("lane_chunk", [1, 0])
+    def test_every_scope_tags_the_compiled_engine(self, stacked, lane_chunk):
+        text = _engine_hlo(stacked, lane_chunk=lane_chunk, **self.KW)
+        scope = re.compile(r"(?<![\w.])(alt\.\w+|neumann)(?![\w.])")
+        paths = [scope.findall(o) for o in re.findall(r'op_name="([^"]*)"', text)]
+        for name in obs_trace.ENGINE_SCOPES:
+            assert any(name in p for p in paths), name
+        # each layer holds instructions of its own, not only nested scopes
+        innermost = {p[-1] for p in paths if p}
+        for name in ("alt.placement", "alt.forwarding", "alt.round_eval",
+                     "neumann"):
+            assert name in innermost, name
+
+    @pytest.mark.parametrize("lane_chunk", [1, 0])
+    def test_scopes_add_no_operation(self, stacked, lane_chunk, monkeypatch):
+        """The compiled engine with its scopes is the engine without them,
+        once metadata and names are set aside, and solves bit for bit the
+        same."""
+        import contextlib
+
+        from repro.core import engine as engine_mod
+        from repro.kernels.neumann import ops as neumann_ops
+
+        kw = dict(lane_chunk=lane_chunk, **self.KW)
+        scoped = _engine_hlo(stacked, **kw)
+        out = engine_solve(stacked, **kw)
+        jax.clear_caches()
+        for mod in (engine_mod, neumann_ops):
+            monkeypatch.setattr(
+                mod, "engine_scope", lambda name: contextlib.nullcontext()
+            )
+        try:
+            plain = _engine_hlo(stacked, **kw)
+            ref = engine_solve(stacked, **kw)
+        finally:
+            jax.clear_caches()
+        assert "alt.engine" in scoped and "alt.engine" not in plain
+        assert _canonical_hlo(scoped) == _canonical_hlo(plain)
+        for key in ("J", "hosts", "history", "iters"):
+            np.testing.assert_array_equal(
+                np.asarray(out[key]), np.asarray(ref[key]), err_msg=key
+            )
+
+    def test_unknown_scope_raises(self):
+        with pytest.raises(ValueError):
+            obs_trace.engine_scope("alt.nothing")
+
+
 # ---------------------------------------------------------------------------
 # Layer 2: host spans + validator
 # ---------------------------------------------------------------------------
@@ -196,6 +275,19 @@ class TestTracer:
         names = [e.name for e in tr.events()]
         assert names.index("child") < names.index("root")
         assert root.attrs == {"kind": "outer"}
+
+    def test_execute_splits_into_dispatch_and_wait(self):
+        """With the tracer on, `solve_fleet.execute` holds a dispatch span
+        and a wait span, and lasts at least as long as both."""
+        obs_trace.configure(enabled=True)
+        obs_trace.reset()
+        solve_fleet(sample_fleet(2, seed=22), m_max=3, t_phi=2)
+        events = obs_trace.TRACER.events()
+        (execute,) = [e for e in events if e.name == "solve_fleet.execute"]
+        kids = {e.name: e for e in events if e.parent == execute.id}
+        assert set(kids) == {"solve_fleet.dispatch", "solve_fleet.wait"}
+        assert execute.dur >= sum(e.dur for e in kids.values())
+        assert kids["solve_fleet.dispatch"].ts <= kids["solve_fleet.wait"].ts
 
     def test_jsonl_roundtrip_validates(self, tmp_path):
         tr = obs_trace.Tracer()
