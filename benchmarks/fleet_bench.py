@@ -9,8 +9,8 @@ cold caches (symmetric: each gets `jax.clear_caches()` first), then warm
 as a paired median of `WARM_REPS` interleaved repeats (see `_paired_warm`)
 for the steady-state re-optimization rate. The engine runs its default
 round-body layout (`lane_chunk` auto ->
-lax.map lane chunks when unsharded, DESIGN.md section 18), which is what
-closed the historical ~0.65x warm gap; the full tier asserts
+lax.map lane chunks on the CPU, DESIGN.md section 18), which is what
+closed the historical ~0.65x warm gap there; the full tier asserts
 `warm_batched_vs_sequential_ratio >= 1.0`.
 
 Section 2 (early exit): both paths now run the shared round engine

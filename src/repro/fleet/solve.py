@@ -6,7 +6,7 @@ stack to the shared device-resident round engine (core/engine.py): the whole
 ALT pipeline — structured init, placement reassignment, forwarding sweeps,
 objective, best-iterate/stall/freeze bookkeeping — runs as ONE jitted
 program over the instance axis (a lockstep while_loop vmapped over lanes
-when a mesh is committed, lane-major `lax.map` chunks otherwise — see
+on a TPU or a committed mesh, lane-major `lax.map` chunks on a CPU — see
 `lane_chunk`). There is no fleet-local copy of the loop body any more; the
 sequential solvers in core/alt.py run the exact same engine at B=1, so the
 two paths share every future fix.
@@ -421,6 +421,38 @@ def _plan_mesh(shard: bool, devices: int | None):
     return mesh, n_dev, "sharded"
 
 
+def _auto_lane_chunk(mesh, platform: str) -> int:
+    """The engine layout `lane_chunk=None` resolves to (DESIGN.md section 18).
+
+    A committed mesh needs the fused batch (0). Unsharded, the best layout
+    follows the hardware. On a TPU every round-body operation at fleet
+    shapes is bound by launch latency, not bytes, so one fused operation
+    over all lanes costs little more than over one: on a v5e the fused
+    batch solved `paper4.loadsweep` at 92.1 inst/s against 35.1 lane-major
+    and 42.6-51.5 for lane chunks of 2-10 (PERF.md section 6). On a
+    CPU the fused batch thrashes cache (~1.9x per lane at V=64), and
+    lane-major (1) wins; any other platform keeps that measured CPU
+    layout."""
+    if mesh is not None or platform == "tpu":
+        return 0
+    return 1
+
+
+def _lane_trips(iters: np.ndarray, lane_chunk: int) -> int:
+    """Lane-trips the engine's loops ran over one chunk's lanes (pad lanes
+    included), from the per-lane `iters` the gather already holds.
+
+    Lanes run in lockstep groups — all of them fused (0), or `lax.map`'s
+    groups of `lane_chunk` consecutive lanes, the remainder last — and each
+    group's loop runs until its slowest lane froze, a lane's own trips
+    being its `iters`. Lane-trips minus Σ iters were spent on frozen lanes."""
+    size = iters.size if lane_chunk == 0 else lane_chunk
+    return sum(
+        int(iters[i : i + size].max()) * len(iters[i : i + size])
+        for i in range(0, iters.size, size)
+    )
+
+
 def _run_chunk(
     problems, *, envelope, hop_bound, n_parts, round_to, mesh, batch_to,
     solve_kw, warm=None,
@@ -494,7 +526,11 @@ def _run_chunk(
     obs_registry.counter(
         "fleet.compile.cold" if cold else "fleet.compile.warm"
     ).inc()
-    with span("solve_fleet.execute", batch=target, cold_compile=cold):
+    lane_chunk = solve_kw["lane_chunk"]
+    with span(
+        "solve_fleet.execute", batch=target, cold_compile=cold,
+        layout={0: "fused", 1: "lane_major"}.get(lane_chunk, lane_chunk),
+    ):
         # dispatch: argument handling, input transfer and enqueue
         with span("solve_fleet.dispatch"):
             out = _solve_fleet_stacked(
@@ -590,10 +626,11 @@ def solve_fleet(
                  its phi-shaped buffers stay cache-resident across rounds
                  and a converged lane stops computing immediately instead
                  of riding lockstep until the slowest lane stalls. Results
-                 are bitwise-identical across layouts. None (default) =
-                 auto: lane-major when unsharded, fused vmap when a mesh is
-                 committed. Asking for a nonzero chunk together with
-                 shard=True raises.
+                 are bitwise-identical across layouts on the CPU. None
+                 (default) = auto, by the platform the engine runs on
+                 (`_auto_lane_chunk`): fused on a TPU or under a committed
+                 mesh, lane-major otherwise. Asking for a nonzero chunk
+                 together with shard=True raises.
     interpret  : with use_pallas=True, run the kernel bodies under the Pallas
                  interpreter (CPU tests only); ignored when
                  use_pallas=False.
@@ -658,7 +695,7 @@ def solve_fleet(
             "use lane_chunk=0 (or leave it None) when shard=True"
         )
     if lane_chunk is None:
-        lane_chunk = 0 if mesh is not None else 1
+        lane_chunk = _auto_lane_chunk(mesh, jax.default_backend())
     solve_kw["lane_chunk"] = lane_chunk
 
     if envelope_cap_gb is not None:
@@ -801,6 +838,15 @@ def solve_fleet(
             state=kept_state,
         )
 
+    # Lockstep waste, from the gathered per-lane iters (no device sync):
+    # lane-trips executed vs the rounds the lanes applied.
+    trips = sum(_lane_trips(hc["iters"], lane_chunk) for hc in host)
+    applied = sum(int(hc["iters"].sum()) for hc in host)
+    obs_registry.counter("fleet.lane_trips").inc(trips)
+    obs_registry.counter("fleet.lane_trips_frozen").inc(trips - applied)
+    obs_registry.gauge("fleet.lockstep_waste_fraction").set(
+        0.0 if trips == 0 else 1.0 - applied / trips
+    )
     obs_registry.counter("fleet.chunks_executed").inc(len(outs))
     obs_registry.gauge("fleet.rounds_executed").set(result.rounds)
     obs_registry.gauge("fleet.m_max").set(result.m_max)

@@ -128,9 +128,9 @@ def main(argv=None) -> int:
         default=None,
         help="round-body layout over the instance axis: 0 = fused vmap (the "
         "only layout compatible with --shard), k >= 1 = lax.map over k-lane "
-        "chunks (faster warm on a single host). Default: auto (chunked when "
-        "unsharded, vmap when a mesh is committed); bitwise-identical "
-        "results either way",
+        "chunks (faster warm on a CPU). Default: auto (vmap on a TPU or "
+        "when a mesh is committed, chunked otherwise); bitwise-identical "
+        "results either way on the CPU",
     )
     ap.add_argument(
         "--trace-out",

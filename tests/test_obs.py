@@ -416,6 +416,58 @@ class TestMetrics:
         )
 
 
+class TestLaneLayout:
+    """The engine layout a solve ran under (`solve_fleet.execute`'s
+    `layout`) and its lockstep waste (`fleet.lane_trips{,_frozen}`)."""
+
+    @pytest.mark.parametrize("lane_chunk", [0, 1, 3])
+    def test_lockstep_waste_from_the_gathered_result(
+        self, lane_chunk, monkeypatch
+    ):
+        """Lane-trips are B x trips fused and Σ iters lane-major, and are
+        read from the one device_get of the gather: no sync is added."""
+        fleet = sample_fleet(5, seed=23)
+        fetches = []
+        device_get = jax.device_get
+        monkeypatch.setattr(
+            jax, "device_get", lambda t: fetches.append(1) or device_get(t)
+        )
+        obs_metrics.registry.reset()
+        res = solve_fleet(fleet, lane_chunk=lane_chunk, **KW)
+        snap = obs_metrics.registry.snapshot()
+        assert len(fetches) == 1
+        applied = int(res.iters.sum())
+        trips = snap["fleet.lane_trips"]
+        assert trips - snap["fleet.lane_trips_frozen"] == applied
+        waste = snap["fleet.lockstep_waste_fraction"]
+        if lane_chunk == 0:
+            assert len(set(res.iters.tolist())) > 1  # some lanes froze early
+            assert trips == len(fleet) * res.rounds
+            assert waste == pytest.approx(
+                1.0 - applied / (len(fleet) * res.rounds)
+            )
+            assert waste > 0.0
+        elif lane_chunk == 1:
+            assert trips == applied and waste == 0.0
+        else:
+            it = res.iters
+            assert trips == 3 * it[:3].max() + 2 * it[3:].max()
+
+    @pytest.mark.parametrize(
+        "lane_chunk, layout", [(0, "fused"), (1, "lane_major"), (3, 3)]
+    )
+    def test_execute_span_names_the_layout(self, lane_chunk, layout):
+        obs_trace.configure(enabled=True)
+        obs_trace.reset()
+        solve_fleet(sample_fleet(3, seed=22), lane_chunk=lane_chunk,
+                    m_max=3, t_phi=2)
+        (execute,) = [
+            e for e in obs_trace.TRACER.events()
+            if e.name == "solve_fleet.execute"
+        ]
+        assert execute.attrs["layout"] == layout
+
+
 # ---------------------------------------------------------------------------
 # Launch CLI integration
 # ---------------------------------------------------------------------------

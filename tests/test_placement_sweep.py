@@ -19,10 +19,12 @@ pinned here:
     and unmoved partitions score unchanged (hypothesis property over random
     connected instances + deterministic anchors);
   * lane_chunk — the engine's round-body layout knob is bitwise-inert
-    unsharded, and `solve_fleet` rejects a nonzero lane_chunk combined with
-    a committed mesh (the guard only fires when a mesh actually commits, so
-    that test runs under the simulated 8-device CPU mesh like
-    tests/test_sharded_fleet.py);
+    unsharded (also on a fleet padded like the benchmark's paper4 cell),
+    `lane_chunk=None` resolves by platform (fused on a TPU or a mesh,
+    lane-major on a CPU), and `solve_fleet` rejects a nonzero lane_chunk
+    combined with a committed mesh (the guard only fires when a mesh
+    actually commits, so that test runs under the simulated 8-device CPU
+    mesh like tests/test_sharded_fleet.py);
   * Apsp0Cache — `repair_fleet` with a cached zero-load APSP is bitwise the
     uncached path, and `refresh_apsp0` hits exactly when (adj, mu, cost)
     are value-identical.
@@ -50,7 +52,9 @@ from repro.core import (
 from repro.core.placement import repair_phi
 from repro.core.marginals import cost_to_go
 from repro.core.structs import one_hot
-from repro.fleet import pad_problem_parts, sample_fleet, solve_fleet
+from repro.fleet import load_grid, pad_problem_parts, sample_fleet, solve_fleet
+from repro.fleet.solve import _auto_lane_chunk
+from repro.obs import trace as obs_trace
 from repro.kernels.minplus import apsp_with_nexthop
 
 N_DEV = len(jax.devices())
@@ -361,7 +365,61 @@ def _fleet():
     ]
 
 
+def _paper4_fleet():
+    """The paper's four topologies at a low and a high load, padded
+    together as in the benchmark's paper4 cell: V 17-30, A 20-40."""
+    names = ("iot", "mesh", "smallworld", "geant")
+    return [p for n in names for p in load_grid(SCENARIOS[n], (0.4, 1.2))]
+
+
 class TestLaneChunk:
+    @pytest.mark.parametrize(
+        "platform, meshed, expected",
+        [("tpu", False, "fused"), ("cpu", False, "lane_major"),
+         ("cpu", True, "fused")],
+    )
+    def test_lane_chunk_auto_resolves_by_platform(
+        self, platform, meshed, expected, monkeypatch
+    ):
+        """lane_chunk=None takes the fused batch on a TPU and under a
+        mesh, lane-major on a CPU; the unsharded cases run through
+        solve_fleet with the platform probe patched and read the decision
+        off the `solve_fleet.execute` span."""
+        mesh = object() if meshed else None
+        lane_chunk = _auto_lane_chunk(mesh, platform)
+        assert lane_chunk == {"fused": 0, "lane_major": 1}[expected]
+        if meshed:
+            return  # the solve itself: test_lane_chunk_auto_resolves_fused_on_mesh
+        monkeypatch.setattr(jax, "default_backend", lambda: platform)
+        obs_trace.configure(enabled=True)
+        obs_trace.reset()
+        try:
+            solve_fleet(_fleet(), **FLEET_KW)
+            (execute,) = [
+                e for e in obs_trace.TRACER.events()
+                if e.name == "solve_fleet.execute"
+            ]
+        finally:
+            obs_trace.configure(enabled=False)
+            obs_trace.reset()
+        assert execute.attrs["layout"] == expected
+
+    @pytest.mark.parametrize("lane_chunk", [0, 3])
+    def test_paper4_fleet_bitwise_across_layouts(self, lane_chunk):
+        """A heterogeneous paper4-like fleet whose lanes freeze at
+        different rounds gives per-lane J, hosts, iters and history
+        bitwise-equal to lane-major: the fused lockstep trips ride the
+        frozen lanes masked."""
+        fleet = _paper4_fleet()
+        kw = dict(FLEET_KW, m_max=6, patience=2)
+        base = solve_fleet(fleet, lane_chunk=1, **kw)
+        assert len(set(base.iters.tolist())) > 1
+        got = solve_fleet(fleet, lane_chunk=lane_chunk, **kw)
+        np.testing.assert_array_equal(got.J, base.J)
+        np.testing.assert_array_equal(got.hosts, base.hosts)
+        np.testing.assert_array_equal(got.iters, base.iters)
+        np.testing.assert_array_equal(got.history, base.history)
+
     def test_lane_chunk_bitwise_inert(self):
         fleet = _fleet()
         base = solve_fleet(fleet, lane_chunk=1, **FLEET_KW)
